@@ -49,17 +49,24 @@ from repro.core.lif import DEFAULT_TAU, DEFAULT_VTH
 BM, BK, BN = 128, 128, 128
 
 
+def _bit_plane(a_block: jax.Array, t: int, dtype) -> jax.Array:
+    """Timestep ``t`` of (bm, bk) uint32 spike words as {0,1} ``dtype``.
+
+    The masked bit goes through int32 on its way to float: Mosaic has no
+    uint32 -> float32 cast (the interpreter accepts one, so only a compile
+    for the chip catches it).  The value is 0 or 1, so the detour is exact.
+    """
+    bit = (a_block >> jnp.uint32(t)) & jnp.uint32(1)
+    return bit.astype(jnp.int32).astype(dtype)
+
+
 def _unpack_fold(a_block: jax.Array, T: int, acc_dtype) -> jax.Array:
     """(bm, bk) uint32 -> (T*bm, bk) {0,1} bit-planes, T-major.
 
     VPU work: one shift+and per timestep; the fold lets a single MXU call
     process all T planes with one weight tile (the `parallel-for t`).
     """
-    bm, bk = a_block.shape
-    planes = [
-        ((a_block >> jnp.uint32(t)) & jnp.uint32(1)).astype(acc_dtype)
-        for t in range(T)
-    ]
+    planes = [_bit_plane(a_block, t, acc_dtype) for t in range(T)]
     return jnp.concatenate(planes, axis=0)  # (T*bm, bk)
 
 
@@ -283,9 +290,7 @@ def _ftp_bsr_adaptive_kernel(
 
             @pl.when(tmap_ref[t] > 0)
             def _(t=t):
-                plane = ((a_word >> jnp.uint32(t)) & jnp.uint32(1)).astype(
-                    jnp.float32
-                )
+                plane = _bit_plane(a_word, t, jnp.float32)
                 acc_ref[t * bm : (t + 1) * bm, :] += jnp.dot(
                     plane, b, preferred_element_type=jnp.float32
                 )

@@ -34,7 +34,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -109,10 +108,17 @@ def _pad_to(x: jax.Array, mults: tuple[int, ...]) -> jax.Array:
     return x
 
 
+def _row_block(M: int, bm: int = _k.BM) -> int:
+    """Row block for ``M`` kernel rows: ``bm``, shrunk for small problems
+    to ``M`` rounded up to the 8-row f32 sublane tile (rows are padded up
+    to it, so a 12-row decode batch runs as one 16-row block)."""
+    return min(bm, -(-max(8, M) // 8) * 8)
+
+
 def _pick_blocks(M, K, N, bm, bk, bn):
     """Shrink default blocks for small problems (still 8/128-aligned when
     possible; interpret mode accepts anything)."""
-    return min(bm, max(8, M)), min(bk, max(8, K)), min(bn, max(128, N) if N >= 128 else N)
+    return _row_block(M, bm), min(bk, max(8, K)), min(bn, max(128, N) if N >= 128 else N)
 
 
 # ---------------------------------------------------------------------------
@@ -219,12 +225,12 @@ def _spmm_sharded(a_packed, b, T, bm, bk, bn, interpret, mesh):
         return _spmm(a_loc, b_loc, T, bm=bm, bk=bk, bn=bn,
                      interpret=interpret)
 
-    out = shard_map(
+    out = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(row, None), P(None, "model")),
         out_specs=P(None, row, "model"),
-        check_rep=False,
+        check_vma=False,
     )(a_packed, b)
     # gather columns back to the canonical layout (see _bsr_call_sharded)
     return jax.lax.with_sharding_constraint(
@@ -358,19 +364,19 @@ def _bsr_call_sharded(
         plan_l = jax.tree.map(lambda x: x[0], plan_loc)
         # caller-supplied bm is honored; default adapts to the LOCAL row
         # count (rows are already divided over `data` here)
-        bm_l = min(_k.BM, max(8, a_loc.shape[0])) if bm is None else bm
+        bm_l = _row_block(a_loc.shape[0]) if bm is None else bm
         return _bsr_call(
             a_loc, plan_l, T, v_th, tau, bm_l, plan_l.n_padded, fuse_lif,
             interpret, adaptive=adaptive, min_spikes=min_spikes,
         )
 
     c_spec = P(row, "model") if fuse_lif else P(None, row, "model")
-    c, u = shard_map(
+    c, u = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(row, None), P("model")),
         out_specs=(c_spec, P(row, "model")),
-        check_rep=False,  # no replication rule for pallas_call
+        check_vma=False,  # no replication rule for pallas_call
     )(a_packed, plan)
     # Gather the column slabs back to the canonical activation layout (rows
     # on `data`, features replicated) RIGHT HERE: without this, the 'model'
@@ -385,6 +391,42 @@ def _bsr_call_sharded(
     if fuse_lif:
         return gather(c, P(row, None))[:, :n_out], u
     return gather(c, P(None, row, None))[:, :, :n_out], u
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=(
+        "T", "v_th", "tau", "bm", "n_out", "fuse_lif", "interpret", "mesh",
+        "adaptive", "min_spikes",
+    ),
+)
+def _bsr_call_rows(
+    a_packed, plan, T, v_th, tau, bm, n_out, fuse_lif, interpret, mesh,
+    adaptive=False, min_spikes=1,
+):
+    """shard_map entry for a whole (unsplit) plan under a multi-device mesh:
+    the plan replicated, spike rows on `data` (when divisible).  Mosaic
+    kernels cannot be partitioned automatically, so even a mesh with no
+    model split runs the kernel per shard.  Rows are independent, so this
+    is the single-device result bit for bit; only adaptive min_spikes>1
+    scores each shard's own rows, as `_bsr_call_sharded` does."""
+    row = _row_axis(mesh, a_packed.shape[0])
+
+    def body(a_loc, plan_loc):
+        bm_l = _row_block(a_loc.shape[0]) if bm is None else bm
+        return _bsr_call(
+            a_loc, plan_loc, T, v_th, tau, bm_l, n_out, fuse_lif, interpret,
+            adaptive=adaptive, min_spikes=min_spikes,
+        )
+
+    c_spec = P(row, None) if fuse_lif else P(None, row, None)
+    return jax.shard_map(
+        body,
+        mesh=mesh,
+        in_specs=(P(row, None), P()),
+        out_specs=(c_spec, P(row, None)),
+        check_vma=False,  # no replication rule for pallas_call
+    )(a_packed, plan)
 
 
 def _bsr(
@@ -413,7 +455,8 @@ def _bsr(
     `dispatch` with a mesh placement), a plan carrying a leading model-shard
     axis (`join_plan.shard_plan`) dispatches to the shard_map entry: each
     model shard joins its own column slab of the static plan against the
-    device-local activity map.
+    device-local activity map.  A whole plan under a multi-device mesh runs
+    per data shard of the rows (`_bsr_call_rows`).
     """
     interpret = (not _on_tpu()) if interpret is None else interpret
     mesh = get_serve_mesh()
@@ -434,9 +477,13 @@ def _bsr(
             a_packed, plan, T, v_th, tau, bm, n_out, fuse_lif, interpret,
             mesh, adaptive=adaptive, min_spikes=min_spikes,
         )
-    M = a_packed.shape[0]
-    bm = min(_k.BM, max(8, M)) if bm is None else bm
     n_out = plan.n_padded if n_out is None else n_out
+    if mesh is not None and mesh.size > 1:
+        return _bsr_call_rows(
+            a_packed, plan, T, v_th, tau, bm, n_out, fuse_lif, interpret,
+            mesh, adaptive=adaptive, min_spikes=min_spikes,
+        )
+    bm = _row_block(a_packed.shape[0]) if bm is None else bm
     return _bsr_call(
         a_packed, plan, T, v_th, tau, bm, n_out, fuse_lif, interpret,
         adaptive=adaptive, min_spikes=min_spikes,

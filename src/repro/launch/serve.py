@@ -62,6 +62,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import warnings
 
 import jax
@@ -349,9 +350,11 @@ def main(argv=None):
     import dataclasses
 
     from repro.configs import get_config, smoke_variant
+    from repro.launch.compile_cache import configure_compile_cache
     from repro.models.registry import build_model
     from repro.serve import Engine, check_parity
 
+    configure_compile_cache(os.getcwd())  # run from the checkout's root
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_variant(cfg)

@@ -38,7 +38,7 @@ def _random_spikes(T: int, n: int, density: float, seed: int) -> np.ndarray:
     return (rng.random((T, n)) < density).astype(np.float32)
 
 
-@settings(max_examples=30)
+@settings(max_examples=30, deadline=None)
 @given(
     T=st.integers(min_value=1, max_value=MAX_T),
     density=st.floats(min_value=0.0, max_value=1.0),
@@ -53,7 +53,7 @@ def test_popcount_equals_time_sum(T, density, seed):
     )
 
 
-@settings(max_examples=30)
+@settings(max_examples=30, deadline=None)
 @given(
     T=st.integers(min_value=1, max_value=MAX_T),
     density=st.floats(min_value=0.0, max_value=1.0),
@@ -68,7 +68,7 @@ def test_timestep_popcount_equals_plane_sum(T, density, seed):
     np.testing.assert_array_equal(got, s.sum(axis=1).astype(np.int32))
 
 
-@settings(max_examples=30)
+@settings(max_examples=30, deadline=None)
 @given(
     T=st.integers(min_value=1, max_value=MAX_T),
     density=st.floats(min_value=0.0, max_value=1.0),
@@ -80,7 +80,7 @@ def test_pack_unpack_roundtrip(T, density, seed):
     np.testing.assert_array_equal(np.asarray(unpack_spikes(packed, T)), s)
 
 
-@settings(max_examples=25)
+@settings(max_examples=25, deadline=None)
 @given(
     T=st.integers(min_value=1, max_value=MAX_T),
     min_spikes=st.integers(min_value=1, max_value=4),
@@ -99,7 +99,7 @@ def test_mask_low_activity_idempotent(T, min_spikes, density, seed):
     assert np.all((pc == 0) | (pc >= min_spikes))
 
 
-@settings(max_examples=25)
+@settings(max_examples=25, deadline=None)
 @given(
     T=st.integers(min_value=1, max_value=MAX_T),
     min_spikes=st.integers(min_value=1, max_value=4),
@@ -118,7 +118,7 @@ def test_mask_low_activity_timesteps_idempotent(T, min_spikes, density, seed):
     assert np.all((tpc == 0) | (tpc >= min_spikes))
 
 
-@settings(max_examples=25)
+@settings(max_examples=25, deadline=None)
 @given(
     T=st.integers(min_value=1, max_value=MAX_T),
     density=st.floats(min_value=0.0, max_value=1.0),
@@ -196,7 +196,7 @@ def _event_plane_oracle(ev, height, width, T, window_us, t0):
     return plane
 
 
-@settings(max_examples=25)
+@settings(max_examples=25, deadline=None)
 @given(
     T=st.integers(min_value=1, max_value=MAX_T),
     n=st.integers(min_value=0, max_value=96),
@@ -229,7 +229,7 @@ def test_encode_event_window_roundtrip(T, n, window_us, t0_windows, seed):
     )
 
 
-@settings(max_examples=25)
+@settings(max_examples=25, deadline=None)
 @given(
     T=st.integers(min_value=1, max_value=MAX_T),
     window_us=st.sampled_from([1, 13, 1000]),
@@ -264,7 +264,7 @@ def test_encode_event_window_boundary_exactness(T, window_us, t0_windows):
     assert (out_words == 0).all()
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(T=st.integers(min_value=1, max_value=MAX_T))
 def test_encode_event_window_empty_is_all_silent(T):
     """An empty window encodes to the all-silent frame: zero words, zero
