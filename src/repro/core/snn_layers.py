@@ -239,12 +239,14 @@ def spiking_ffn_apply_packed(
     if cfg.preprocess_min_spikes > 0:
         from .packing import mask_low_activity
 
-        pm = mask_low_activity(pm, cfg.preprocess_min_spikes)
+        with jax.named_scope("ffn.encode"):
+            pm = mask_low_activity(pm, cfg.preprocess_min_spikes)
     if plan_in is not None:
         packed_h, o = _ffn_dual_sparse(pm, plan_in, plan_out, w_in, w_out, cfg)
     else:
-        packed_h, _ = ftp_layer(pm, w_in, cfg.T, cfg.v_th, cfg.tau)
-        o = ftp_spmspm(packed_h, w_out, cfg.T)
+        packed_h = _ffn_up_lif(pm, w_in, cfg)
+        with jax.named_scope("ffn.down"):
+            o = ftp_spmspm(packed_h, w_out, cfg.T)
     y = rate_decode(o)
     return (
         y.reshape(*lead, -1),
@@ -252,22 +254,35 @@ def spiking_ffn_apply_packed(
     )
 
 
+def _ffn_up_lif(pm, w_in, cfg: SpikingConfig):
+    """The jnp hidden layer: up GEMM (``ffn.up``), then the P-LIF epilogue
+    (``ffn.lif``); packed hidden words out."""
+    with jax.named_scope("ffn.up"):
+        o = ftp_spmspm(pm, w_in, cfg.T)
+    with jax.named_scope("ffn.lif"):
+        spikes, _ = lif_forward(o, v_th=cfg.v_th, tau=cfg.tau, unroll=True)
+        return pack_spikes(spikes)
+
+
 def _ffn_dual_sparse(pm, plan_in, plan_out, w_in, w_out, cfg: SpikingConfig):
     """Both FFN GEMMs through the dual-sparse BSR kernel: fused P-LIF on the
     hidden layer (packed words out), plain full sums on the output layer.
-    Returns (packed hidden words (M, F), full sums (T, M, D))."""
+    Returns (packed hidden words (M, F), full sums (T, M, D)).  The LIF runs
+    inside the ``ffn.up`` kernel, so no operation carries ``ffn.lif``."""
     from repro.kernels import ops
     from repro.serve.policy import PACKED_DUAL
 
-    packed_h, _ = ops.dispatch(
-        pm, plan_in, PACKED_DUAL, cfg.T,
-        fuse_lif=True, v_th=cfg.v_th, tau=cfg.tau,
-        n_out=w_in.shape[1],
-    )
-    o, _ = ops.dispatch(
-        packed_h, plan_out, PACKED_DUAL, cfg.T,
-        fuse_lif=False, n_out=w_out.shape[1],
-    )
+    with jax.named_scope("ffn.up"):
+        packed_h, _ = ops.dispatch(
+            pm, plan_in, PACKED_DUAL, cfg.T,
+            fuse_lif=True, v_th=cfg.v_th, tau=cfg.tau,
+            n_out=w_in.shape[1],
+        )
+    with jax.named_scope("ffn.down"):
+        o, _ = ops.dispatch(
+            packed_h, plan_out, PACKED_DUAL, cfg.T,
+            fuse_lif=False, n_out=w_out.shape[1],
+        )
     return packed_h, o
 
 
@@ -298,7 +313,8 @@ def spiking_ffn_apply(
     lead = x.shape[:-1]
     d_model = x.shape[-1]
     xm = x.reshape(-1, d_model)  # (M, K)
-    spikes_in = direct_encode(xm, cfg.T, v_th=cfg.v_th, tau=cfg.tau)
+    with jax.named_scope("ffn.encode"):
+        spikes_in = direct_encode(xm, cfg.T, v_th=cfg.v_th, tau=cfg.tau)
 
     if mode == "train":
         if cfg.weight_density < 1.0:
@@ -309,11 +325,14 @@ def spiking_ffn_apply(
         o = ftp_spmspm_unpacked(hidden, w_out)               # (T, M, D)
         y = rate_decode(o)
     elif mode == "infer":
-        packed_in = pack_spikes(spikes_in)
-        if cfg.preprocess_min_spikes > 0:
-            from .packing import mask_low_activity
+        with jax.named_scope("ffn.encode"):
+            packed_in = pack_spikes(spikes_in)
+            if cfg.preprocess_min_spikes > 0:
+                from .packing import mask_low_activity
 
-            packed_in = mask_low_activity(packed_in, cfg.preprocess_min_spikes)
+                packed_in = mask_low_activity(
+                    packed_in, cfg.preprocess_min_spikes
+                )
         if plan_in is not None:
             _, o = _ffn_dual_sparse(
                 packed_in, plan_in, plan_out, w_in, w_out, cfg
@@ -322,14 +341,17 @@ def spiking_ffn_apply(
             from repro.kernels import ops
             from repro.serve.policy import PACKED_DENSE
 
-            packed_h, _ = ops.dispatch(
-                packed_in, w_in, PACKED_DENSE, cfg.T,
-                fuse_lif=True, v_th=cfg.v_th, tau=cfg.tau,
-            )
-            o = ops.dispatch(packed_h, w_out, PACKED_DENSE, cfg.T)
+            with jax.named_scope("ffn.up"):
+                packed_h, _ = ops.dispatch(
+                    packed_in, w_in, PACKED_DENSE, cfg.T,
+                    fuse_lif=True, v_th=cfg.v_th, tau=cfg.tau,
+                )
+            with jax.named_scope("ffn.down"):
+                o = ops.dispatch(packed_h, w_out, PACKED_DENSE, cfg.T)
         else:
-            packed_h, _ = ftp_layer(packed_in, w_in, cfg.T, cfg.v_th, cfg.tau)
-            o = ftp_spmspm(packed_h, w_out, cfg.T)
+            packed_h = _ffn_up_lif(packed_in, w_in, cfg)
+            with jax.named_scope("ffn.down"):
+                o = ftp_spmspm(packed_h, w_out, cfg.T)
         y = rate_decode(o)
     else:
         raise ValueError(f"unknown mode {mode!r}")

@@ -432,17 +432,19 @@ def mlp_apply(p, x, cfg: ArchConfig):
             plans=plans,
         )
         return y.astype(x.dtype)
-    if cfg.act == "swiglu":
-        h = jax.nn.silu(xc @ p["wg"].astype(_ct(cfg))) * (xc @ p["wu"].astype(_ct(cfg)))
-    elif cfg.act == "geglu":
-        h = jax.nn.gelu(xc @ p["wg"].astype(_ct(cfg))) * (xc @ p["wu"].astype(_ct(cfg)))
-    elif cfg.act == "sq_relu":
-        h = jnp.square(jax.nn.relu(xc @ p["wu"].astype(_ct(cfg))))
-    elif cfg.act == "gelu":
-        h = jax.nn.gelu(xc @ p["wu"].astype(_ct(cfg)))
-    else:
-        raise ValueError(cfg.act)
-    return (h @ p["wd"].astype(_ct(cfg))).astype(x.dtype)
+    with jax.named_scope("ffn.up"):
+        if cfg.act == "swiglu":
+            h = jax.nn.silu(xc @ p["wg"].astype(_ct(cfg))) * (xc @ p["wu"].astype(_ct(cfg)))
+        elif cfg.act == "geglu":
+            h = jax.nn.gelu(xc @ p["wg"].astype(_ct(cfg))) * (xc @ p["wu"].astype(_ct(cfg)))
+        elif cfg.act == "sq_relu":
+            h = jnp.square(jax.nn.relu(xc @ p["wu"].astype(_ct(cfg))))
+        elif cfg.act == "gelu":
+            h = jax.nn.gelu(xc @ p["wu"].astype(_ct(cfg)))
+        else:
+            raise ValueError(cfg.act)
+    with jax.named_scope("ffn.down"):
+        return (h @ p["wd"].astype(_ct(cfg))).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------

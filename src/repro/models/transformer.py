@@ -65,20 +65,25 @@ def block_axes(cfg: ArchConfig) -> dict:
 
 
 def block_apply(p, x, cfg: ArchConfig, positions=None, cache=None):
-    """Pre-norm transformer block. Returns (x, new_cache, aux_loss)."""
-    h, new_cache = attn_apply(
-        p["attn"], rmsnorm(x, p["ln1"], cfg.norm_eps), cfg,
-        positions=positions, cache=cache,
-    )
-    x = x + h
-    x = _shard_hook(x, "residual")
-    h2 = rmsnorm(x, p["ln2"], cfg.norm_eps)
-    if cfg.n_experts:
-        h2, aux = moe_apply(p["moe"], h2, cfg)
-    else:
-        h2, aux = mlp_apply(p["mlp"], h2, cfg), 0.0
-    x = x + h2
-    x = _shard_hook(x, "residual")
+    """Pre-norm transformer block. Returns (x, new_cache, aux_loss).
+
+    Its two halves are the named scopes ``attention`` and ``ffn`` of the
+    compiled program (norm and residual add included)."""
+    with jax.named_scope("attention"):
+        h, new_cache = attn_apply(
+            p["attn"], rmsnorm(x, p["ln1"], cfg.norm_eps), cfg,
+            positions=positions, cache=cache,
+        )
+        x = x + h
+        x = _shard_hook(x, "residual")
+    with jax.named_scope("ffn"):
+        h2 = rmsnorm(x, p["ln2"], cfg.norm_eps)
+        if cfg.n_experts:
+            h2, aux = moe_apply(p["moe"], h2, cfg)
+        else:
+            h2, aux = mlp_apply(p["mlp"], h2, cfg), 0.0
+        x = x + h2
+        x = _shard_hook(x, "residual")
     return x, new_cache, aux
 
 
@@ -148,6 +153,7 @@ def _stack_forward(p_layers, x, cfg: ArchConfig, positions):
     return x, aux
 
 
+@jax.named_scope("embed")
 def embed_tokens(p, cfg: ArchConfig, tokens):
     e = p["embed"][tokens].astype(_ct(cfg))
     if cfg.name.startswith("gemma"):
@@ -174,6 +180,14 @@ def forward(p, cfg: ArchConfig, batch: dict):
     x, aux = _stack_forward(p["layers"], x, cfg, positions)
     x = rmsnorm(x, p["final_norm"], cfg.norm_eps)
     return x, aux
+
+
+@jax.named_scope("head")
+def head(p, cfg: ArchConfig, x, last: bool = False):
+    """Final norm and unembedding (of the last position alone with
+    ``last``): the named scope ``head``."""
+    x = rmsnorm(x, p["final_norm"], cfg.norm_eps)
+    return unembed(p, cfg, x[:, -1:] if last else x)
 
 
 def unembed(p, cfg: ArchConfig, x):
@@ -306,8 +320,7 @@ def prefill(p, cfg: ArchConfig, batch: dict, cache):
         }
     else:
         x, new_cache = _stack_forward_cached(p["layers"], x, cfg, positions, cache)
-    x = rmsnorm(x, p["final_norm"], cfg.norm_eps)
-    return unembed(p, cfg, x[:, -1:]), new_cache
+    return head(p, cfg, x, last=True), new_cache
 
 
 def decode_step(p, cfg: ArchConfig, tokens, cache):
@@ -326,5 +339,4 @@ def decode_step(p, cfg: ArchConfig, tokens, cache):
         cache["pos"][None, None] + jnp.arange(S)[None, :], (B, S)
     )
     x, new_cache = _stack_forward_cached(p["layers"], x, cfg, positions, cache)
-    x = rmsnorm(x, p["final_norm"], cfg.norm_eps)
-    return unembed(p, cfg, x), new_cache
+    return head(p, cfg, x), new_cache

@@ -241,6 +241,18 @@ def bucket_key(prompt_len: int, align: int = 1) -> int:
 # Packed-spike activation cache
 # ---------------------------------------------------------------------------
 
+def spike_sparsity_of(words: np.ndarray, T: int) -> float:
+    """Fraction of (neuron, timestep) positions with no spike in a
+    (rows, width) batch of packed uint32 words (1.0 for no rows)."""
+    words = np.ascontiguousarray(words, np.uint32)
+    if words.size == 0:
+        return 1.0
+    fired = np.unpackbits(
+        words.view(np.uint8), bitorder="little"
+    ).reshape(words.shape[0], words.shape[1], 32)[..., :T]
+    return float(1.0 - fired.mean())
+
+
 @dataclass
 class PackedSpikeCache:
     """Carries per-slot SNN activations between engine steps as packed
@@ -306,15 +318,18 @@ class PackedSpikeCache:
         self._sync()
         self.words = self.words[np.asarray(idx, np.int64)]
 
+    def latest(self):
+        """The newest words, without a device-to-host copy: the staged
+        device words while an async update is pending, else the host
+        words."""
+        if self._pending_dev is not None:
+            return self._pending_dev
+        return self.words
+
     def spike_sparsity(self) -> float:
         """Fraction of (neuron, timestep) positions with no spike."""
         self._sync()
-        if self.words.size == 0:
-            return 1.0
-        fired = np.unpackbits(
-            self.words.view(np.uint8), bitorder="little"
-        ).reshape(self.words.shape[0], self.width, 32)[..., : self.T]
-        return float(1.0 - fired.mean())
+        return spike_sparsity_of(self.words, self.T)
 
     def silent_fraction(self) -> float:
         """Fraction of silent neurons (word == 0) — droppable entirely."""

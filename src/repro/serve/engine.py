@@ -96,8 +96,8 @@ import numpy as np
 from repro.core.lif import direct_encode
 from repro.core.packing import pack_spikes
 
-from .batching import DenseCacheOps, PackedSpikeCache
-from .executor import make_executor
+from .batching import DenseCacheOps, PackedSpikeCache, spike_sparsity_of
+from .executor import make_executor, span
 from .metrics import EngineMetrics, RequestMetrics
 from .policy import ExecutionPolicy
 from .scheduler import AdmissionTicket, Request, RequestState, Scheduler
@@ -306,7 +306,9 @@ class Engine:
         # plans; per-request only the spike side of the join runs, on
         # device, inside the kernel.
         self.spiking_dual_sparse = policy.weight_sparsity == "dual_sparse"
-        self._last_spike_sparsity = float("nan")
+        # the newest packed spike words an encode produced (host or device);
+        # `summary()` scores them, so no step pays for the telemetry
+        self._last_spike_words = None
         self._spike_pool = None
         if self.paged and self.spiking_packed:
             from .paging import SpikeSlotPool
@@ -772,7 +774,9 @@ class Engine:
         toks = jnp.asarray(
             [st.generated[-1] for st in cohort.slots], jnp.int32
         )
-        words = np.asarray(self._encode_pack(self.params, toks))
+        words_dev = self._encode_pack(self.params, toks)
+        with span("serve.encode.wait"):
+            words = np.asarray(words_dev)
         self.record_timestep_skips(words)
         return words
 
@@ -1215,7 +1219,11 @@ class Engine:
         if not self.policy.token_identical:
             s["drift_tol"] = self.policy.exactness.tol
         if self.spiking_packed:
-            s["spike_sparsity"] = self._last_spike_sparsity
+            w = self._last_spike_words
+            s["spike_sparsity"] = (
+                float("nan") if w is None
+                else spike_sparsity_of(np.asarray(w), self.cfg.spiking_T)
+            )
             s["spike_bytes_packed_per_slot"] = self.cfg.d_model * 4
             s["spike_bytes_unpacked_f32_per_slot"] = (
                 self.cfg.d_model * self.cfg.spiking_T * 4

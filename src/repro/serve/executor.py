@@ -56,6 +56,16 @@ Every stage is timed into `EngineMetrics.stage_s` (surfaced by
 ``sync`` the per-step host wait shows up in ``sample_sync``; under
 ``pipelined`` the decode stage is dispatch-only and the deferred drain
 overlaps in-flight device work.
+
+The same stage clock marks each stage as a profiler span ``serve.<stage>``
+(`jax.profiler.TraceAnnotation`, on the clock of the device trace), inside
+one ``serve.step`` step span per `step()`.  Spans carry request identity
+while a trace is active: ``prefill`` its ``rids``, ``rows`` and prompt
+``length``; ``decode`` its ``rows``, ``live`` rows and attended ``length``;
+``retire`` the ``rids`` it finishes.  The host's wait for device values has
+child spans of its own: ``serve.sample_sync.wait`` (sampled tokens) and
+``serve.encode.wait`` (packed spike words).  With no trace active a span
+costs the profiler's enabled check and nothing else.
 """
 from __future__ import annotations
 
@@ -64,6 +74,7 @@ from dataclasses import dataclass
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from .batching import bucket_key, pad_batch
 from .policy import acceptance_lengths
@@ -82,13 +93,48 @@ class PendingStep:
     logits: object | None = None
 
 
-class _StageClock:
-    """Accumulate wall time per stage into `EngineMetrics.stage_s`."""
+def _open_span(name: str, args=None, cls=TraceAnnotation):
+    """Open a profiler span, or return None when no trace is active.
+    ``args`` (a callable returning the span's arguments) runs only while
+    tracing."""
+    if not TraceAnnotation.is_enabled():
+        return None
+    s = cls(name, **(args() if args is not None else {}))
+    s.__enter__()
+    return s
 
-    def __init__(self, metrics, name: str):
-        self.metrics, self.name = metrics, name
+
+class span:
+    """A profiler span alone (no stage time): ``with span(name): ...``."""
+
+    __slots__ = ("name", "_s")
+
+    def __init__(self, name: str):
+        self.name = name
 
     def __enter__(self):
+        self._s = _open_span(self.name)
+        return self
+
+    def __exit__(self, *exc):
+        if self._s is not None:
+            self._s.__exit__(*exc)
+        return False
+
+
+class _StageClock:
+    """Accumulate wall time per stage into `EngineMetrics.stage_s`, under a
+    profiler span ``serve.<stage>`` whose arguments ``args()`` gives.
+    ``trace`` is the open span while a trace is active (arguments known
+    only inside the stage go to ``trace.set_metadata``), else None."""
+
+    __slots__ = ("metrics", "name", "args", "t0", "trace")
+
+    def __init__(self, metrics, name: str, args=None):
+        self.metrics, self.name, self.args = metrics, name, args
+
+    def __enter__(self):
+        self.trace = _open_span("serve." + self.name, self.args)
         self.t0 = time.perf_counter()
         return self
 
@@ -97,7 +143,20 @@ class _StageClock:
             self.metrics.stage_s.get(self.name, 0.0)
             + time.perf_counter() - self.t0
         )
+        if self.trace is not None:
+            self.trace.__exit__(*exc)
         return False
+
+
+def _rids(requests) -> str:
+    """Request ids as one span argument: space-separated."""
+    return " ".join(str(r.rid) for r in requests)
+
+
+def _decode_args(cohort, width: int = 1) -> dict:
+    n = len(cohort.slots)
+    return {"rows": n + cohort.n_dummy, "live": n,
+            "length": cohort.length + width}
 
 
 class SyncExecutor:
@@ -112,15 +171,26 @@ class SyncExecutor:
 
     def __init__(self, engine):
         self.engine = engine
+        self.n_steps = 0
 
-    def _clock(self, stage: str) -> _StageClock:
-        return _StageClock(self.engine.metrics, stage)
+    def _clock(self, stage: str, args=None) -> _StageClock:
+        return _StageClock(self.engine.metrics, stage, args)
 
     # -- the step loop (shared scaffold; executors differ only in the
     # per-cohort `decode_cohort` body) ---------------------------------------
     def step(self) -> dict:
         """One engine iteration: admit+prefill, merge, decode/sample/encode
-        per cohort, retire."""
+        per cohort, retire; one ``serve.step`` span in a trace."""
+        self.n_steps += 1
+        s = _open_span("serve.step", lambda: {"step_num": self.n_steps},
+                       cls=StepTraceAnnotation)
+        try:
+            return self._step()
+        finally:
+            if s is not None:
+                s.__exit__(None, None, None)
+
+    def _step(self) -> dict:
         e = self.engine
         t0 = time.perf_counter()
         e.metrics.sample_queue_depth(e.scheduler.queue_depth)
@@ -142,13 +212,13 @@ class SyncExecutor:
             self.ingest()  # stream frames -> chunked incremental prefill
         with self._clock("merge"):
             self.merge()  # flushes merging cohorts (pipelined)
-        with self._clock("retire"):
+        with self._clock("retire", self._retire_args):
             self.retire()  # requests finished at prefill never enter decode
         for cohort in e.cohorts:
             if cohort.stream is not None:
                 continue  # ingesting: generation starts at go-live
             self.decode_cohort(cohort)
-        with self._clock("retire"):
+        with self._clock("retire", self._retire_args):
             self.retire()
         e.metrics.wall_s += time.perf_counter() - t0
         return {
@@ -157,12 +227,17 @@ class SyncExecutor:
             "cohorts": len(e.cohorts),
         }
 
+    def _retire_args(self) -> dict:
+        """Span arguments of ``retire``: the requests it finishes."""
+        return {"rids": _rids(st.request for c in self.engine.cohorts
+                              if not c.pending for st in c.slots if st.done)}
+
     # -- stages -------------------------------------------------------------
     def prefill(self, group: list[Request]) -> None:
         """Batched prefill of one same-bucket group; emits each request's
         first token (TTFT is inherently a host event) and opens a cohort."""
         e = self.engine
-        with self._clock("prefill"):
+        with self._clock("prefill") as clk:
             # bucket_align > 1 (approximate mode): right-pad ragged prompts
             # to the shared bucket length with token 0 — pad tokens are
             # attended, so outputs are approximate; exact mode (align=1)
@@ -174,6 +249,9 @@ class SyncExecutor:
             for i, r in enumerate(group):
                 tokens[i, : r.prompt_len] = r.prompt
             tokens, n_dummy = pad_batch(tokens, e.batch_align)
+            if clk.trace is not None:
+                clk.trace.set_metadata(rids=_rids(group),
+                                       rows=tokens.shape[0], length=P)
             e.metrics.n_padded_rows += n_dummy
             logits, cache = e.dispatch_prefill(tokens)
             e.metrics.n_prefill_batches += 1
@@ -204,7 +282,8 @@ class SyncExecutor:
         ``cohort.pending`` as the go-live candidate (it only becomes the
         first generated token if no further frame arrives)."""
         e = self.engine
-        with self._clock("prefill"):
+        with self._clock("prefill", lambda: {"rids": str(req.rid), "rows": 1,
+                                             "length": 1}):
             f0 = session.frames[0]
             req.prompt = np.asarray([f0.token], np.int32)
             tokens, n_dummy = pad_batch(
@@ -338,10 +417,11 @@ class SyncExecutor:
         e = self.engine
         if self._maybe_speculative(cohort):
             return
-        with self._clock("decode"):
+        with self._clock("decode", lambda: _decode_args(cohort)):
             logits = self._dispatch_decode(cohort)
         with self._clock("sample_sync"):
-            nxt = np.asarray(cohort.next_tokens)
+            with span("serve.sample_sync.wait"):
+                nxt = np.asarray(cohort.next_tokens)
             e._capture(cohort.slots, logits)
             for st, tok in zip(cohort.slots, nxt):
                 st.emit(int(tok), e.eos_id)
@@ -477,16 +557,17 @@ class SyncExecutor:
                 chunk, cohort.draft_cache, k
             )
             e.metrics.n_draft_batches += 1
-        with self._clock("decode"):
+        with self._clock("decode", lambda: _decode_args(cohort, k + 1)):
             verify = jnp.concatenate([pending[:, None], draft_dev], axis=1)
             logits, cohort.cache = e.dispatch_decode(verify, cohort.cache)
             e.metrics.n_decode_batches += 1
             e.metrics.n_decode_rows += len(cohort.slots)
         with self._clock("sample_sync"):
-            tgt = np.asarray(
-                jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            )
-            drafts = np.asarray(draft_dev)
+            with span("serve.sample_sync.wait"):
+                tgt = np.asarray(
+                    jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                )
+                drafts = np.asarray(draft_dev)
             acc = acceptance_lengths(drafts, tgt)
             live = [i for i, st in enumerate(cohort.slots) if not st.done]
             A = int(min((int(acc[i]) for i in live), default=k))
@@ -530,8 +611,9 @@ class SyncExecutor:
         e = self.engine
         if not e.spiking_packed:
             return
-        cohort.spikes.update(e._slot_spikes(cohort))
-        e._last_spike_sparsity = cohort.spikes.spike_sparsity()
+        words = e._slot_spikes(cohort)
+        cohort.spikes.update(words)
+        e._last_spike_words = words
 
     def retire(self) -> None:
         """Drop finished requests, gather surviving cache rows, release
@@ -624,10 +706,10 @@ class PipelinedExecutor(SyncExecutor):
         self.engine.metrics.n_straggler_events += 1
         self._force_repack = True
 
-    def step(self) -> dict:
+    def _step(self) -> dict:
         e = self.engine
         decode_before = e.metrics.stage_s.get("decode", 0.0)
-        out = super().step()
+        out = super()._step()
         decode_delta = e.metrics.stage_s.get("decode", 0.0) - decode_before
         if decode_delta > 0.0:  # only steps that actually decoded
             self.step_timer.observe(decode_delta)
@@ -667,7 +749,7 @@ class PipelinedExecutor(SyncExecutor):
             # speculative rounds are synchronous (see `speculative_round`):
             # no PendingStep enters the window
             return
-        with self._clock("decode"):
+        with self._clock("decode", lambda: _decode_args(cohort)):
             logits = self._dispatch_decode(cohort)
             cohort.pending.append(PendingStep(
                 tokens=cohort.next_tokens,
@@ -719,7 +801,8 @@ class PipelinedExecutor(SyncExecutor):
         logits.  Returns True when a slot finished (EOS or budget)."""
         e = self.engine
         p = cohort.pending.pop(0)
-        toks = np.asarray(p.tokens)
+        with span("serve.sample_sync.wait"):
+            toks = np.asarray(p.tokens)
         if p.logits is not None:
             e._capture(cohort.slots, np.asarray(p.logits)[:, None])
         for st, tok in zip(cohort.slots, toks):
@@ -736,7 +819,7 @@ class PipelinedExecutor(SyncExecutor):
         while cohort.pending:
             self._materialize(cohort)
         if self.engine.spiking_packed and cohort.spikes is not None:
-            self.engine._last_spike_sparsity = cohort.spikes.spike_sparsity()
+            self.engine._last_spike_words = cohort.spikes.latest()
             # decode-step encodes stayed on device (update_async); score the
             # flushed state so temporal='adaptive' telemetry reflects this
             # executor too (a sampled lower bound — see EngineMetrics)

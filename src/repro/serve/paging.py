@@ -56,7 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .batching import CacheOps, _axes_leaves
+from .batching import CacheOps, _axes_leaves, spike_sparsity_of
 
 
 class PagePoolExhausted(RuntimeError):
@@ -142,6 +142,7 @@ class PageLayout:
             )
 
     # -- per-leaf gather/scatter (pure data movement) -----------------------
+    @jax.named_scope("kv_gather")
     def _gather_leaves(self, pools, seq_table, state_table, locals_):
         """Rebuild the dense cache view from the pools (bitwise equal to
         the dense layout's cache for the same history)."""
@@ -172,6 +173,7 @@ class PageLayout:
         leaves = jax.tree.leaves(cache)
         return [leaves[i] for i in self.local_idx]
 
+    @jax.named_scope("kv_scatter")
     def _scatter_all(self, pools, cache, seq_table, state_table):
         """Write every page of every row (prefill: the whole view is new,
         including the zero tail — so freshly allocated pages need no
@@ -195,6 +197,7 @@ class PageLayout:
                 pools[key] = pools[key].at[state_table].set(x)
         return pools
 
+    @jax.named_scope("kv_scatter")
     def _scatter_step(self, pools, cache, seq_table, state_table, pos,
                       span: int = 1):
         """Write back one decode dispatch: the sequence pages the write of
@@ -664,15 +667,14 @@ class PagedSpikeCache:
         self.pool.free(self.row_ids[~keep])
         self.row_ids = self.row_ids[idx]
 
+    def latest(self):
+        if self._pending_dev is not None:
+            return self._pending_dev
+        return self.words
+
     # -- telemetry (same formulas as PackedSpikeCache) ----------------------
     def spike_sparsity(self) -> float:
-        w = self.words
-        if w.size == 0:
-            return 1.0
-        fired = np.unpackbits(
-            np.ascontiguousarray(w).view(np.uint8), bitorder="little"
-        ).reshape(w.shape[0], self.width, 32)[..., : self.T]
-        return float(1.0 - fired.mean())
+        return spike_sparsity_of(self.words, self.T)
 
     def silent_fraction(self) -> float:
         w = self.words
