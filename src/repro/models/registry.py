@@ -7,6 +7,8 @@
     prefill(params, batch, cache) -> (logits, cache)
     decode(params, tokens, cache) -> (logits, cache)
     init_cache(batch, max_len) -> cache     cache_axes() -> axes tree
+    serving_params(params) -> params (the tree the serving engine's step
+        programs read; identity unless the family defines it)
     input_spec(shape_cell) handled by repro.launch.specs.
 """
 from __future__ import annotations
@@ -31,6 +33,7 @@ class Model:
     decode: Callable
     init_cache: Callable
     cache_axes: Callable
+    serving_params: Callable = lambda p: p
 
 
 def build_model(cfg: ArchConfig) -> Model:
@@ -44,6 +47,7 @@ def build_model(cfg: ArchConfig) -> Model:
             decode=lambda p, t, c: transformer.decode_step(p, cfg, t, c),
             init_cache=lambda b, s: transformer.init_cache(cfg, b, s),
             cache_axes=lambda: transformer.cache_axes(cfg),
+            serving_params=lambda p: transformer.serving_params(p, cfg),
         )
     if cfg.family == "ssm":
         return Model(

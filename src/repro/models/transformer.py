@@ -113,6 +113,41 @@ def init_params(cfg: ArchConfig, key) -> dict:
     return p
 
 
+def serving_params(p, cfg: ArchConfig) -> dict:
+    """The param tree as the serving step programs read it.
+
+    Every leaf the model reads only through ``.astype(compute_dtype)`` is
+    stored in the compute dtype once, so a step program reads it directly
+    instead of converting the whole stacked tensor on every call: the
+    attention projections, the untied ``lm_head``, ``mm_proj``, and the
+    dense or expert MLP weights.  The values reaching each dot are the ones
+    the in-program cast gave (both round to nearest even).  Left as they
+    are: ``embed`` (the packed spike encode and a tied head read it in
+    f32), the norm scales, the MoE ``router``, and the spiking FFN's
+    ``wu``/``wd`` (`layers.attach_spiking_ffn_plans` builds its own
+    compute-dtype payload from them).  A leaf already in the compute dtype
+    is returned as it is, so the transform is idempotent.  Training keeps
+    its f32 masters and never calls this."""
+    ct = _ct(cfg)
+
+    def cast(a):
+        return a if a.dtype == ct else a.astype(ct)
+
+    layers = dict(p["layers"])
+    layers["attn"] = {k: cast(v) if k in ("wq", "wk", "wv", "wo") else v
+                      for k, v in layers["attn"].items()}
+    if "moe" in layers:
+        layers["moe"] = {k: v if k == "router" else cast(v)
+                         for k, v in layers["moe"].items()}
+    elif not cfg.spiking_ffn:
+        layers["mlp"] = {k: cast(v) for k, v in layers["mlp"].items()}
+    out = dict(p, layers=layers)
+    for k in ("lm_head", "mm_proj"):
+        if k in out:
+            out[k] = cast(out[k])
+    return out
+
+
 def logical_axes(cfg: ArchConfig) -> dict:
     ax: dict = {}
     if cfg.embed_inputs:

@@ -103,6 +103,15 @@ from .policy import ExecutionPolicy
 from .scheduler import AdmissionTicket, Request, RequestState, Scheduler
 
 
+def _precast_bytes(base, served) -> int:
+    """Bytes, in their source dtype, of the leaves `serving_params` cast."""
+    return sum(
+        int(a.nbytes)
+        for a, b in zip(jax.tree.leaves(base), jax.tree.leaves(served))
+        if a.dtype != b.dtype
+    )
+
+
 @dataclass
 class Cohort:
     """A set of in-flight requests sharing one batched cache.
@@ -182,8 +191,8 @@ class Engine:
         mesh = policy.mesh
         self.model = model
         # the UNTRANSFORMED host param tree: `_configure_placement` derives
-        # self.params (sharded, join plans attached) from it, and `remesh`
-        # re-derives from it for a different mesh
+        # self.params (cast leaves, sharded, join plans attached) from it,
+        # and `remesh` re-derives from it for a different mesh
         self._base_params = params
         self.cfg = cfg
         self.max_len = max_len
@@ -323,12 +332,13 @@ class Engine:
 
     def _configure_placement(self, policy: ExecutionPolicy) -> None:
         """(Re)derive every placement-dependent attribute from ``policy``:
-        admission batch alignment, params placement (model-axis sharding
-        BEFORE join plans attach, while the tree still matches the model's
-        logical-axes tree), and the jitted dispatch callables — which
-        capture the mesh at trace time and therefore must be rebuilt on
-        `remesh`.  Always derives from `_base_params`, so re-configuring
-        is idempotent and mesh-agnostic."""
+        admission batch alignment, params placement (`serving_params`
+        casts, then model-axis sharding BEFORE join plans attach, while the
+        tree still matches the model's logical-axes tree), and the jitted
+        dispatch callables — which capture the mesh at trace time and
+        therefore must be rebuilt on `remesh`.  Always derives from
+        `_base_params`, so re-configuring is idempotent and
+        mesh-agnostic."""
         self.policy = policy
         mesh = policy.mesh
         self.mesh = mesh
@@ -340,7 +350,10 @@ class Engine:
             # so fresh cohorts shard evenly down the mesh from step one
             dn = mesh.shape.get("data", 1)
             self.batch_align = max(self.batch_align, dn)
-        params = self._base_params
+        # leaves the step programs read only in the compute dtype are cast
+        # once here, not in every call (`transformer.serving_params`)
+        served = params = self.model.serving_params(self._base_params)
+        self.metrics.precast_bytes = _precast_bytes(self._base_params, served)
         if mesh is not None:
             # weights on the model axis; the POLICY picks the dim set —
             # reduction-free under bitwise exactness, psum-TP attention/MLP
@@ -395,9 +408,9 @@ class Engine:
                 donate_argnums=(2,),
             ))
         if self.speculative:
-            self._configure_draft(policy)
+            self._configure_draft(policy, served)
 
-    def _configure_draft(self, policy: ExecutionPolicy) -> None:
+    def _configure_draft(self, policy: ExecutionPolicy, served) -> None:
         """Derive the draft policy's params/plans/jits next to the target's.
 
         The draft runs the SAME base weights on the SAME mesh placement;
@@ -407,10 +420,12 @@ class Engine:
         Rebuilt by every `_configure_placement` call, so `remesh` re-shards
         the draft exactly like the target.  Propose jits are built lazily
         per (catchup, k) — at most two trace shapes per k in steady state.
+        ``served`` is the target's `serving_params` tree, whose cast leaves
+        the draft shares.
         """
         spec = policy.speculation
         mesh = self.mesh
-        params = self._base_params
+        params = served
         if spec.draft_weight_density is not None:
             from repro.models.layers import derive_draft_params
 

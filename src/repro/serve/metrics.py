@@ -103,16 +103,25 @@ class EngineMetrics:
     # deferred drain that overlaps in-flight device work — the breakdown
     # that makes the pipelined-vs-sync difference attributable.
     stage_s: dict[str, float] = field(default_factory=dict)
+    # Bytes, in the source dtype, of the param leaves the engine's placement
+    # cast to the compute dtype once (`Model.serving_params`): what each
+    # step program no longer converts.  0 where nothing was cast (params
+    # already in the compute dtype, or a model without the hook).  Set once
+    # per placement, so `reset()` keeps it.
+    precast_bytes: int = 0
 
     def record(self, m: RequestMetrics) -> None:
         self.completed.append(m)
 
     def reset(self) -> None:
         """Zero every aggregate back to a fresh engine's state — the
-        measurement-window boundary the class docstring promises.  The
-        instance is reset in place so `engine.metrics` references (executor
-        stage clocks, CacheStore move counters) stay live."""
+        measurement-window boundary the class docstring promises; the
+        placement's ``precast_bytes`` stays.  The instance is reset in place
+        so `engine.metrics` references (executor stage clocks, CacheStore
+        move counters) stay live."""
         for f in fields(self):
+            if f.name == "precast_bytes":
+                continue
             setattr(self, f.name,
                     f.default_factory() if f.default_factory is not MISSING
                     else f.default)
@@ -182,5 +191,6 @@ class EngineMetrics:
             "remeshes": self.n_remeshes,
             "straggler_events": self.n_straggler_events,
             "max_queue_depth": self.max_queue_depth,
+            "precast_bytes": self.precast_bytes,
             "stage_s": {k: self.stage_s[k] for k in sorted(self.stage_s)},
         }
