@@ -24,20 +24,34 @@ class ArchConfig:
     act: str = "swiglu"         # swiglu | geglu | sq_relu | gelu
     qk_norm: bool = False
     attn: str = "causal"        # causal | bidir | swa
-    window: int = 4096          # SWA window
+    window: int = 4096          # SWA window (and the sliding layers' window)
+    # Per-layer attention kind, Hugging Face's ``layer_types``: each entry
+    # "sliding_attention" (causal, last `window` positions) or
+    # "full_attention"; the first n_layers entries apply.  Empty: every
+    # layer runs `attn`.  Every layer keeps one full-length KV cache.
+    layer_types: tuple[str, ...] = ()
     # GQA x TP: when n_kv doesn't divide the model axis but n_heads does,
     # expand K/V to all heads at use time (Megatron-style KV replication) so
     # attention intermediates stay head-sharded.  Measured 250x memory-term
     # reduction on nemotron train_4k (EXPERIMENTS.md §Perf).
     expand_kv: bool = False
     rope_theta: float = 500000.0
+    # YaRN RoPE on the full-attention layers of a `layer_types` model
+    # (Hugging Face's rope_type "yarn"); 0 = off.  ``yarn_attention_factor``
+    # 0 takes YaRN's default, 0.1 ln(factor) + 1.
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 0.0
     tie_embeddings: bool = False
     norm_eps: float = 1e-5
 
     # MoE
     n_experts: int = 0
     top_k: int = 2
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25   # dense experts only: spiking ones drop none
+    d_expert: int = 0               # expert width; 0 = d_ff
 
     # SSM (mamba2 / rwkv6)
     ssm_state: int = 0
@@ -98,7 +112,7 @@ class ArchConfig:
                 per_layer += 2 * D * self.n_kv * self.head_dim     # k, v
                 per_layer += self.n_heads * self.head_dim * D      # o
             n_mats = 3 if self.act in ("swiglu", "geglu") else 2
-            ffn = n_mats * D * F
+            ffn = n_mats * D * (self.expert_width if self.n_experts else F)
             if self.n_experts:
                 per_layer += self.n_experts * ffn + D * self.n_experts
             else:
@@ -127,10 +141,15 @@ class ArchConfig:
         """Active (per-token) params — differs from n_params for MoE."""
         if not self.n_experts:
             return self.n_params()
-        D, F, L = self.d_model, self.d_ff, self.n_layers
+        D, F, L = self.d_model, self.expert_width, self.n_layers
         n_mats = 3 if self.act in ("swiglu", "geglu") else 2
         inactive = L * (self.n_experts - self.top_k) * n_mats * D * F
         return self.n_params() - inactive
+
+    @property
+    def expert_width(self) -> int:
+        """Each expert's FFN width."""
+        return self.d_expert or self.d_ff
 
 
 @dataclass(frozen=True)

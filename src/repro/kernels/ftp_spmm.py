@@ -1,6 +1,6 @@
 """Pallas TPU kernels for the FTP dataflow (DESIGN.md §3).
 
-Three kernels:
+Three kernels (and their grouped form, kernel 4 below):
 
 * ``ftp_spmm``            — packed spikes x dense weights -> (T, M, N) sums.
 * ``ftp_spmm_fused_lif``  — same, with the P-LIF epilogue fused in VMEM;
@@ -403,3 +403,149 @@ def ftp_spmm_bsr(
         interpret=interpret,
     )(*prefetch, a_packed, b_vals)
     return c, u
+
+
+# ---------------------------------------------------------------------------
+# Kernel 4: grouped dual-sparse BSR — one call over all experts of a layer.
+#
+# The rows arrive sorted by expert and padded per expert to the row tile
+# (`ops.group_rows`), so every (bm, .) row tile belongs to one expert.  The
+# tile's expert rides in as one more scalar-prefetch operand (``groups``:
+# the expert of each tile, then the live tile count) and selects that
+# expert's join lists and payload blocks; the weight join, the spike join
+# and the fused epilogue are the dense BSR kernel's.  Steps of tiles past
+# the last live one, and join slots past a column's count, repeat the
+# previous step's block indices: no DMA is issued for them and the body
+# does no MXU work, so a call reads the kept blocks of the experts that
+# hold rows and nothing else.  The join lists are flattened to 1-D so the
+# scalar memory holds them unpadded.
+# ---------------------------------------------------------------------------
+
+def grouped_slot(i, j, jj, cnt, groups, *, nm, nnb, jmax):
+    """(row tile, expert, flat join slot) whose blocks grid step
+    (i, j, jj) fetches.  Live steps: their own tile and column, the join
+    slot clamped to the column's last live one.  Steps of dead tiles
+    (i >= the live count): the last live step's, so nothing is fetched."""
+    n_live = groups[nm]
+    live = i < n_live
+    it = jnp.where(live, i, n_live - 1)
+    jt = jnp.where(live, j, nnb - 1)
+    e = groups[it]
+    last = jnp.maximum(cnt[e * nnb + jt] - 1, 0)
+    st = jnp.where(live, jnp.minimum(jj, last), last)
+    return it, jt, e, (e * nnb + jt) * jmax + st
+
+
+def rate_epilogue(acc, T: int):
+    """(T*bm, bn) full sums -> (bm, bn) firing-rate decode: the mean over
+    the T timestep planes, summed in timestep order."""
+    bm = acc.shape[0] // T
+    s = acc[0:bm]
+    for t in range(1, T):
+        s = s + acc[t * bm:(t + 1) * bm]
+    return s / T
+
+
+def _ftp_bsr_grouped_kernel(
+    kidx_ref, vidx_ref, cnt_ref, act_ref, grp_ref,   # scalar-prefetch
+    a_ref, bv_ref, c_ref, acc_ref,
+    *, T, nm, nnb, nkb, jmax, v_th, tau, fuse_lif,
+):
+    i = pl.program_id(0)
+    j = pl.program_id(1)
+    jj = pl.program_id(2)
+    live = i < grp_ref[nm]
+    e = grp_ref[i]
+    col = e * nnb + j
+
+    @pl.when(jnp.logical_and(live, jj == 0))
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    kb = kidx_ref[col * jmax + jj]
+    joined = jnp.logical_and(jj < cnt_ref[col], act_ref[i * nkb + kb] > 0)
+
+    @pl.when(jnp.logical_and(live, joined))
+    def _():
+        a = _unpack_fold(a_ref[...], T, jnp.float32)
+        b = bv_ref[0, 0].astype(jnp.float32)
+        acc_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+    @pl.when(jnp.logical_and(live, jj == jmax - 1))
+    def _():
+        if fuse_lif:
+            packed, _ = _lif_epilogue(acc_ref[...], T, v_th, tau)
+            c_ref[...] = packed
+        else:
+            c_ref[...] = rate_epilogue(acc_ref[...], T)
+
+
+def ftp_spmm_bsr_grouped(
+    a_packed: jax.Array,
+    b_vals: jax.Array,
+    kidx: jax.Array,
+    vidx: jax.Array,
+    cnt: jax.Array,
+    act: jax.Array,
+    groups: jax.Array,
+    N: int,
+    T: int,
+    v_th: float = DEFAULT_VTH,
+    tau: float = DEFAULT_TAU,
+    *,
+    bm: int = BM,
+    fuse_lif: bool = True,
+    interpret: bool = False,
+):
+    """Dual-sparse FTP spMspM of expert-grouped rows over per-expert plans.
+
+    a_packed: (nm * bm, K) uint32 packed spikes, rows sorted by expert and
+              padded per expert to ``bm`` (`ops.group_rows`).
+    b_vals:   (E, nnzb, bk, bn) each expert's block-CSR payload.
+    kidx, vidx: (E, nnb, jmax) int32 per-expert join lists; cnt: (E, nnb).
+    act:      (nm, nkb) int32 spike block-activity map.
+    groups:   (nm + 1,) int32 — the expert of each row tile, then the live
+              tile count (tiles past it are padding and skipped).
+    Returns (nm * bm, N): packed uint32 spikes with ``fuse_lif`` (the LIF
+    epilogue), else float32 rates (the mean of the T full sums).  Rows of
+    dead tiles are left unwritten.
+    """
+    M, K = a_packed.shape
+    E, nnzb, bk, bn = b_vals.shape
+    _, nnb, jmax = kidx.shape
+    nm, nkb = act.shape
+    assert M == nm * bm and K == nkb * bk and N == nnb * bn
+    assert groups.shape == (nm + 1,)
+    geo = dict(nm=nm, nnb=nnb, jmax=jmax)
+
+    def a_map(i, j, jj, kidx, vidx, cnt, act, grp):
+        it, _, _, slot = grouped_slot(i, j, jj, cnt, grp, **geo)
+        return it, kidx[slot]
+
+    def b_map(i, j, jj, kidx, vidx, cnt, act, grp):
+        _, _, e, slot = grouped_slot(i, j, jj, cnt, grp, **geo)
+        return e, vidx[slot], 0, 0
+
+    def c_map(i, j, jj, kidx, vidx, cnt, act, grp):
+        it, jt, _, _ = grouped_slot(i, j, jj, cnt, grp, **geo)
+        return it, jt
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(nm, nnb, jmax),
+        in_specs=[pl.BlockSpec((bm, bk), a_map),
+                  pl.BlockSpec((1, 1, bk, bn), b_map)],
+        out_specs=pl.BlockSpec((bm, bn), c_map),
+        scratch_shapes=[pltpu.VMEM((T * bm, bn), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(
+            _ftp_bsr_grouped_kernel, T=T, nm=nm, nnb=nnb, nkb=nkb,
+            jmax=jmax, v_th=v_th, tau=tau, fuse_lif=fuse_lif,
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(
+            (M, N), jnp.uint32 if fuse_lif else jnp.float32),
+        interpret=interpret,
+    )(kidx.reshape(-1), vidx.reshape(-1), cnt.reshape(-1), act.reshape(-1),
+      groups, a_packed, b_vals)

@@ -12,7 +12,9 @@ One front door: ``dispatch(a, weights_or_plan, policy, T)`` routes by the
   kernel (load-time weight join + device-side spike join; sharded plans
   dispatch through shard_map under the policy/serve mesh);
 * ``weight_sparsity='dual_sparse'`` + raw (pruned) weights -> convenience:
-  plan built per call, then the BSR kernel.
+  plan built per call, then the BSR kernel;
+* a plan stacked over experts + ``groups`` -> the grouped BSR kernel over
+  rows sorted and padded by expert (`group_rows`; spiking-MoE layers).
 
 The wrappers handle padding to MXU-aligned blocks and backend dispatch
 (interpret=True off-TPU so the kernels are validated everywhere; compiled on
@@ -522,6 +524,83 @@ def _bsr_batched(
     return out.reshape(T, B, M, N), u.reshape(B, M, N)
 
 
+# ---------------------------------------------------------------------------
+# Grouped dual-sparse entry: the routed rows of a spiking-MoE layer, sorted
+# and padded by expert (`group_rows`), through one BSR call per GEMM over
+# all experts' plans (stacked on a leading expert axis).
+# ---------------------------------------------------------------------------
+
+def grouped_row_block(rows: int, n_groups: int) -> int:
+    """Row tile for ``rows`` routed rows over ``n_groups`` experts: the
+    mean rows per expert, rounded up to the 8-row sublane tile, at most
+    `_k.BM` (a one-token decode runs 8-row tiles, a long prefill 128)."""
+    return _row_block(-(-rows // n_groups))
+
+
+def grouped_tiles(rows: int, n_groups: int, bm: int) -> int:
+    """Row tiles enough for any routing of ``rows`` rows over ``n_groups``
+    experts, each expert's rows padded to ``bm``: a static bound, so the
+    grid never depends on the routing."""
+    return (rows + min(n_groups, rows) * (bm - 1)) // bm
+
+
+def group_rows(eidx, n_groups: int, bm: int):
+    """Sort routed (row, slot) pairs by expert and pad each expert's rows
+    to ``bm``, on the device.
+
+    eidx: (N, k) int expert of each pair.  Returns ``src`` ((nm * bm,)
+    int32: the input row of each padded row, N for padding), ``dest``
+    ((N, k) int32: the padded row of each pair) and ``groups`` ((nm + 1,)
+    int32: each tile's expert, then the live tile count; dead tiles carry
+    the last live tile's expert).  Within an expert, pairs keep their
+    (row, slot) order."""
+    N, k = eidx.shape
+    rows = N * k
+    nm = grouped_tiles(rows, n_groups, bm)
+    flat = eidx.reshape(rows).astype(jnp.int32)
+    onehot = jax.nn.one_hot(flat, n_groups, dtype=jnp.int32)
+    rank = jnp.take_along_axis(jnp.cumsum(onehot, axis=0), flat[:, None],
+                               axis=1)[:, 0] - 1
+    counts = jnp.sum(onehot, axis=0)
+    padded = -(-counts // bm) * bm
+    ends = jnp.cumsum(padded)
+    row = (ends - padded)[flat] + rank
+    src = jnp.full((nm * bm,), N, jnp.int32).at[row].set(
+        jnp.arange(rows, dtype=jnp.int32) // k)
+    n_live = ends[-1] // bm
+    tiles = jnp.arange(nm, dtype=jnp.int32)
+    expert = jnp.searchsorted(ends, tiles * bm, side="right").astype(jnp.int32)
+    expert = jnp.where(tiles < n_live, expert, expert[n_live - 1])
+    groups = jnp.concatenate([expert, n_live[None].astype(jnp.int32)])
+    return src, row.reshape(N, k), groups
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("T", "v_th", "tau", "bm", "n_out", "fuse_lif",
+                     "interpret"),
+)
+def _bsr_call_grouped(a_packed, plan, groups, T, v_th, tau, bm, n_out,
+                      fuse_lif, interpret):
+    """(nm * bm, K) expert-grouped packed spikes against an expert-stacked
+    plan -> (nm * bm, n_out) packed spikes (``fuse_lif``) or float32 rates.
+    Named with the `_bsr_call` prefix, by which traces count the kernel."""
+    global BSR_TRACE_COUNT
+    BSR_TRACE_COUNT += 1  # trace-time side effect, by design (see _bsr_call)
+    K = a_packed.shape[1]
+    if K > plan.k_padded:
+        raise ValueError(f"spike width {K} exceeds plan K {plan.k_padded}")
+    ap = (jnp.pad(a_packed, [(0, 0), (0, plan.k_padded - K)])
+          if plan.k_padded > K else a_packed)
+    act = block_activity_map(ap, bm, plan.bk).astype(jnp.int32)
+    c = _k.ftp_spmm_bsr_grouped(
+        ap, plan.payload, plan.kidx, plan.vidx, plan.cnt, act, groups,
+        plan.n_padded, T, v_th, tau, bm=bm, fuse_lif=fuse_lif,
+        interpret=interpret,
+    )
+    return c[:, :n_out]
+
+
 def _dual_sparse_once(
     a_packed: np.ndarray,
     b: np.ndarray,
@@ -574,6 +653,7 @@ def dispatch(
     bk: int | None = None,
     bn: int | None = None,
     interpret: bool | None = None,
+    groups=None,
 ):
     """Run one FTP layer under an `ExecutionPolicy` — the single public
     kernel entry point.
@@ -592,6 +672,12 @@ def dispatch(
     Returns (T, M[, N-batched], N) full sums without ``fuse_lif``; with it,
     (packed spike words | float spikes, membrane potentials) — the LoAS
     fused P-LIF layer in the policy's spike format.
+
+    ``groups`` (`group_rows`): ``a`` is (nm * bm, K) packed rows sorted
+    and padded by expert and the plan is stacked over experts; one grouped
+    BSR call joins each row tile with its expert's plan.  Without
+    ``fuse_lif`` it returns the (nm * bm, N) rate decode (the mean over T)
+    instead of full sums; single device, full temporal walk only.
 
     Dual-sparse with RAW weights builds the plan per call (offline
     convenience); serving paths build plans once at load and pass them in.
@@ -637,6 +723,19 @@ def dispatch(
         return o
 
     mesh = policy.mesh if policy.mesh is not None else get_serve_mesh()
+    if groups is not None:
+        if not plan_like or a.ndim != 2 or policy.temporal.enabled:
+            raise ValueError("a grouped call takes (rows, K) packed spikes, "
+                             "an expert-stacked plan and the full temporal "
+                             "walk")
+        if mesh is not None and mesh.size > 1:
+            raise NotImplementedError("no grouped BSR entry under a mesh")
+        return _bsr_call_grouped(
+            a, weights_or_plan, groups, T, v_th, tau,
+            a.shape[0] // (groups.shape[0] - 1),
+            weights_or_plan.n_padded if n_out is None else n_out, fuse_lif,
+            (not _on_tpu()) if interpret is None else interpret,
+        )
     bm_ = _k.BM if bm is None else bm
     bk_ = _k.BK if bk is None else bk
     bn_ = _k.BN if bn is None else bn

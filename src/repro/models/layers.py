@@ -46,17 +46,79 @@ def rmsnorm(x, scale, eps=1e-5):
 # RoPE
 # ---------------------------------------------------------------------------
 
-def rope_apply(x, positions, theta):
-    """x: (B, S, H, dh); positions: (B, S) int32."""
+def rope_apply(x, positions, theta, freqs=None, scale=None):
+    """x: (B, S, H, dh); positions: (B, S) int32.  ``freqs``/``scale``:
+    a layer's own (half,) inverse frequencies and cos/sin factor
+    (`rope_table`); default: ``theta``'s plain table, factor 1."""
     dh = x.shape[-1]
     half = dh // 2
-    freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if freqs is None:
+        freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     ang = positions.astype(jnp.float32)[..., None] * freqs  # (B, S, half)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def yarn_inv_freq(dh: int, theta: float, factor: float, original_max_pos: int,
+                  beta_fast: float, beta_slow: float):
+    """YaRN's (dh // 2,) inverse frequencies and attention factor, as
+    Hugging Face ``_compute_yarn_parameters`` defines them (``truncate``
+    true): dimensions rotating more than ``beta_fast`` times over the
+    original context keep their frequency, fewer than ``beta_slow`` are
+    divided by ``factor``, with a linear ramp between."""
+    import numpy as np
+
+    half = dh // 2
+    pos_freqs = theta ** (np.arange(0, dh, 2, dtype=np.float32) / dh)
+    extra, inter = 1.0 / pos_freqs, 1.0 / (factor * pos_freqs)
+
+    def dim(rot):
+        return (dh * math.log(original_max_pos / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim(beta_fast)), 0)
+    high = min(math.ceil(dim(beta_slow)), dh - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float32) - low) / (high - low),
+                   0.0, 1.0)
+    extra_share = 1.0 - ramp
+    inv = inter * (1.0 - extra_share) + extra * extra_share
+    return inv.astype(np.float32), 0.1 * math.log(factor) + 1.0
+
+
+def rope_table(cfg: ArchConfig, sliding):
+    """(inverse frequencies, cos/sin factor) of a `layer_types` layer:
+    ``sliding`` (a traced bool) picks the plain table; full-attention
+    layers take YaRN's when ``cfg.yarn_factor`` is set."""
+    half = cfg.head_dim // 2
+    plain = cfg.rope_theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if not cfg.yarn_factor:
+        return plain, None
+    inv, default_af = yarn_inv_freq(
+        cfg.head_dim, cfg.rope_theta, cfg.yarn_factor,
+        cfg.yarn_original_max_pos, cfg.yarn_beta_fast, cfg.yarn_beta_slow)
+    af = cfg.yarn_attention_factor or default_af
+    return (jnp.where(sliding, plain, jnp.asarray(inv)),
+            jnp.where(sliding, jnp.float32(1.0), jnp.float32(af)))
+
+
+def layer_kinds(cfg: ArchConfig):
+    """(n_layers,) bool, True for a sliding-window layer, or None when the
+    model has no per-layer attention kinds."""
+    if not cfg.layer_types:
+        return None
+    kinds = cfg.layer_types[: cfg.n_layers]
+    if len(kinds) != cfg.n_layers or not set(kinds) <= {
+            "sliding_attention", "full_attention"}:
+        raise ValueError(f"{cfg.name}: layer_types {cfg.layer_types!r} do not "
+                         f"give {cfg.n_layers} sliding/full layers")
+    return jnp.asarray([k == "sliding_attention" for k in kinds])
 
 
 # ---------------------------------------------------------------------------
@@ -100,15 +162,20 @@ def attn_axes(cfg: ArchConfig) -> dict:
     return ax
 
 
-def _attn_mask(iq, jk, mode: str, window: int, kv_len=None):
+def _attn_mask(iq, jk, mode: str, window: int, kv_len=None, sliding=None):
     """iq: (cq,) absolute query positions; jk: (Skv,) absolute kv positions
-    (may be a ring buffer's stored positions; -1 = empty slot)."""
+    (may be a ring buffer's stored positions; -1 = empty slot).
+    ``sliding``: a traced bool that adds the window to a causal mask (a
+    `layer_types` layer)."""
     if mode == "bidir":
         m = jnp.ones((iq.shape[0], jk.shape[0]), bool)
     else:
         m = jk[None, :] <= iq[:, None]
         if mode == "swa":
             m &= jk[None, :] > (iq[:, None] - window)
+        if sliding is not None:
+            m &= jnp.logical_or(jnp.logical_not(sliding),
+                                jk[None, :] > (iq[:, None] - window))
     m &= jk[None, :] >= 0
     if kv_len is not None:
         m &= jk[None, :] < kv_len
@@ -117,7 +184,7 @@ def _attn_mask(iq, jk, mode: str, window: int, kv_len=None):
 
 def multihead_attention(
     q, k, v, cfg: ArchConfig, *, q_offset=0, kv_len=None, mode=None,
-    kv_positions=None,
+    kv_positions=None, sliding=None,
 ):
     """q: (B, Sq, H, dh); k, v: (B, Skv, KV, dh) -> (B, Sq, H, dh).
 
@@ -138,7 +205,7 @@ def multihead_attention(
         s = jnp.einsum(
             "bqkgd,bskd->bkgqs", q_c, k, preferred_element_type=jnp.float32
         ) * scale
-        m = _attn_mask(iq, jk, mode, cfg.window, kv_len)
+        m = _attn_mask(iq, jk, mode, cfg.window, kv_len, sliding)
         s = jnp.where(m[None, None, None], s, -1e30)
         p = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum(
@@ -162,12 +229,16 @@ def multihead_attention(
 
 
 def attn_apply(
-    p, x, cfg: ArchConfig, *, positions=None, cache=None, mode=None
+    p, x, cfg: ArchConfig, *, positions=None, cache=None, mode=None,
+    sliding=None,
 ):
     """Full attention sub-block: projections + RoPE (+qk-norm) + attention.
 
     cache: None (training/prefill without cache) or dict(k, v, pos) for
     decode; when given, k/v are written at `pos` and attended with kv_len.
+    ``sliding``: this layer's kind in a `layer_types` model (a traced bool,
+    scanned with the layer): the window mask and the plain RoPE table when
+    true, full attention and the full layers' table (YaRN) when false.
     Returns (out, new_cache).
     """
     B, S, D = x.shape
@@ -184,7 +255,11 @@ def attn_apply(
         k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-    if cfg.attn != "bidir":  # encoders here use absolute embeddings instead
+    if sliding is not None:
+        freqs, scale = rope_table(cfg, sliding)
+        q = rope_apply(q, positions, cfg.rope_theta, freqs, scale)
+        k = rope_apply(k, positions, cfg.rope_theta, freqs, scale)
+    elif cfg.attn != "bidir":  # encoders here use absolute embeddings instead
         q = rope_apply(q, positions, cfg.rope_theta)
         k = rope_apply(k, positions, cfg.rope_theta)
 
@@ -199,7 +274,8 @@ def attn_apply(
     q = _qkv_hook(q)
     new_cache = None
     if cache is None:
-        o = multihead_attention(q, _expand(k), _expand(v), cfg, mode=mode)
+        o = multihead_attention(q, _expand(k), _expand(v), cfg, mode=mode,
+                                sliding=sliding)
     else:
         # Ring-buffer cache: slot = pos % S_cache (for full attention the
         # cache is sized to max_len so slot == pos; for SWA it is sized to
@@ -219,7 +295,7 @@ def attn_apply(
         o = multihead_attention(
             q, _expand(ck.astype(q.dtype), hook=False),
             _expand(cv.astype(q.dtype), hook=False), cfg,
-            q_offset=pos, mode=mode, kv_positions=kv_pos,
+            q_offset=pos, mode=mode, kv_positions=kv_pos, sliding=sliding,
         )
         new_cache = {"k": ck, "v": cv, "kv_pos": kv_pos, "pos": pos + S}
     o = o.reshape(B, S, H * dh)
@@ -310,8 +386,12 @@ def attach_spiking_ffn_plans(
     prune-once density contract, and attaches per-layer `WeightJoinPlan`s
     (``plan_in`` / ``plan_out``).  Stacked layers get `stack_plans`-padded
     plans with a leading layer axis, so they scan with `jax.lax.scan`
-    exactly like the weights.  Host work happens once here; every
-    subsequent forward is device-only.
+    exactly like the weights.  Spiking experts ((L, E, K, N) under a
+    ``moe`` node with its ``router``) get one plan per (layer, expert),
+    stacked over the expert axis and then the layer axis: a scanned layer
+    sees an (E, ...) plan, which the grouped BSR call indexes by each row
+    tile's expert.  Host work happens once here; every subsequent forward
+    is device-only.
 
     ``model_shards > 1`` (mesh serving): each per-layer plan is column-split
     into that many self-contained slabs (`join_plan.shard_plan`) stacked on
@@ -342,25 +422,39 @@ def attach_spiking_ffn_plans(
             )
         return build_weight_plan(w2d)
 
+    def stacked(w):
+        if w.ndim == 2:
+            return one_plan(w)
+        return stack_plans([stacked(w[i]) for i in range(w.shape[0])])
+
     def plans_for(w):
         # payload carries the compute-dtype cast the apply path uses, so the
         # kernel contracts bit-identical values to the dense jnp path
-        w = np.asarray(jnp.asarray(w).astype(ct))
-        if w.ndim == 2:
-            return one_plan(w)
-        return stack_plans([one_plan(w[l]) for l in range(w.shape[0])])
+        return stacked(np.asarray(jnp.asarray(w).astype(ct)))
 
     def prepare(node):
         wu, wd = node["wu"], node["wd"]
+        if "router" in node and model_shards > 1:
+            raise NotImplementedError(
+                "spiking experts have no model-sharded plans; serve them "
+                "on a mesh without a model axis")
         if cfg.spiking_weight_density < 1.0:
             assert_weight_density(wu, cfg.spiking_weight_density)
             assert_weight_density(wd, cfg.spiking_weight_density)
         return dict(node, plan_in=plans_for(wu), plan_out=plans_for(wd))
 
+    return _map_spiking_ffns(params, prepare)
+
+
+def _map_spiking_ffns(params: dict, fn) -> dict:
+    """``params`` with ``fn`` applied to every spiking-FFN node: a ``wu``/
+    ``wd`` pair with no gate, the dense ``mlp`` or the spiking experts of a
+    ``moe`` node (its ``router`` rides along)."""
+
     def walk(node):
         if isinstance(node, dict):
-            if {"wu", "wd"} <= node.keys() and not {"wg", "router"} & node.keys():
-                return prepare(node)
+            if {"wu", "wd"} <= node.keys() and "wg" not in node:
+                return fn(node)
             return {k: walk(v) for k, v in node.items()}
         return node
 
@@ -389,22 +483,19 @@ def derive_draft_params(params: dict, cfg: ArchConfig, density: float) -> dict:
             return jnp.asarray(prune_to_density(w, density))
         import numpy as np
 
+        flat = w.reshape((-1,) + w.shape[-2:])
         return jnp.asarray(
-            np.stack([prune_to_density(w[l], density) for l in range(w.shape[0])])
-        )
+            np.stack([prune_to_density(m, density) for m in flat])
+        ).reshape(w.shape)
 
-    def walk(node):
-        if isinstance(node, dict):
-            if {"wu", "wd"} <= node.keys() and not {"wg", "router"} & node.keys():
-                out = {k: v for k, v in node.items()
-                       if k not in ("plan_in", "plan_out")}
-                out["wu"] = prune(node["wu"])
-                out["wd"] = prune(node["wd"])
-                return out
-            return {k: walk(v) for k, v in node.items()}
-        return node
+    def draft(node):
+        out = {k: v for k, v in node.items()
+               if k not in ("plan_in", "plan_out")}
+        out["wu"] = prune(node["wu"])
+        out["wd"] = prune(node["wd"])
+        return out
 
-    return walk(params)
+    return _map_spiking_ffns(params, draft)
 
 
 def mlp_apply(p, x, cfg: ArchConfig):
@@ -448,13 +539,17 @@ def mlp_apply(p, x, cfg: ArchConfig):
 
 
 # ---------------------------------------------------------------------------
-# MoE (top-k router, capacity-gather dispatch — EP-shardable on `experts`)
+# MoE: dense experts (top-k router, capacity-gather dispatch — EP-shardable
+# on `experts`) and spiking experts (drop-free, grouped BSR)
 # ---------------------------------------------------------------------------
 
 def moe_init(key, cfg: ArchConfig) -> dict:
-    D, F, E = cfg.d_model, cfg.d_ff, cfg.n_experts
+    """Router and expert weights.  Spiking experts (``cfg.spiking_ffn``)
+    are the spiking FFN's two matrices per expert, pruned once here per
+    expert as `mlp_init` prunes the dense one."""
+    D, F, E = cfg.d_model, cfg.expert_width, cfg.n_experts
     ks = jax.random.split(key, 4)
-    glu = cfg.act in ("swiglu", "geglu")
+    glu = cfg.act in ("swiglu", "geglu") and not cfg.spiking_ffn
     p = {
         "router": dense_init(ks[0], (D, E), jnp.float32),
         "wu": dense_init(ks[1], (E, D, F), _dt(cfg), fan_in=D),
@@ -462,6 +557,17 @@ def moe_init(key, cfg: ArchConfig) -> dict:
     }
     if glu:
         p["wg"] = dense_init(ks[3], (E, D, F), _dt(cfg), fan_in=D)
+    if cfg.spiking_ffn and cfg.spiking_weight_density < 1.0:
+        from repro.core.snn_layers import prune_by_magnitude
+        from repro.kernels.join_plan import pick_plan_blocks
+
+        d = cfg.spiking_weight_density
+        for name in ("wu", "wd"):
+            K, N = p[name].shape[1:]
+            bk, bn = pick_plan_blocks(K, N)
+            block = (bk, bn) if (K % bk == 0 and N % bn == 0) else None
+            p[name] = jax.vmap(
+                lambda w: prune_by_magnitude(w, d, block=block))(p[name])
     return p
 
 
@@ -471,9 +577,118 @@ def moe_axes(cfg: ArchConfig) -> dict:
         "wu": ("experts", "d_model", "d_ff"),
         "wd": ("experts", "d_ff", "d_model"),
     }
-    if cfg.act in ("swiglu", "geglu"):
+    if cfg.act in ("swiglu", "geglu") and not cfg.spiking_ffn:
         ax["wg"] = ("experts", "d_model", "d_ff")
     return ax
+
+
+def rows_independent(cfg: ArchConfig) -> bool:
+    """Whether each row of a batch is computed from that row alone.  Only
+    capacity routing (`moe_apply`'s dense experts) couples rows: it drops
+    the tokens past an expert's capacity, and which those are depends on
+    the whole batch.  Spiking experts (`spiking_moe_apply`) drop none."""
+    return not cfg.n_experts or cfg.spiking_ffn
+
+
+def _route(router, xt, n_experts: int, k: int):
+    """Softmax over the router's logits, top ``k``, gates renormalised;
+    the Switch load-balancing loss beside.  Float32 at full precision:
+    the router is tiny, and a rounded logit can swap an expert."""
+    logits = jnp.dot(xt.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate, eidx = jax.lax.top_k(probs, k)                     # (T, k)
+    gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    top1 = jax.nn.one_hot(eidx[:, 0], n_experts, dtype=jnp.float32)
+    aux = n_experts * jnp.sum(jnp.mean(top1, axis=0) * jnp.mean(probs, axis=0))
+    return gate, eidx, aux
+
+
+def spiking_moe_apply(p, x, cfg: ArchConfig):
+    """Spiking dual-sparse experts with drop-free top-k routing.
+
+    x: (B, S, D).  Each token goes to its ``top_k`` experts (softmax, top
+    k, gates renormalised) and gets the gate-weighted sum of their spiking
+    FFNs (direct encode, W_in, LIF, W_out, rate decode).  Every routed
+    (token, expert) pair is computed, whatever the skew, so a row's output
+    depends on that row alone (`rows_independent`).
+
+    Serving (packed inference with join plans attached) sorts the routed
+    rows by expert, pads each expert's rows to the kernel's row tile, and
+    runs each GEMM as one grouped BSR call over all experts (`ops.dispatch`
+    with ``groups``); otherwise every expert runs the float path on every
+    token and the gates select.  Named scopes inside ``ffn``: ``ffn.route``
+    (router, top k, sort and pad), ``ffn.encode``, ``ffn.up``,
+    ``ffn.down``, ``ffn.combine`` (un-sort, gate-weighted sum)."""
+    B, S, D = x.shape
+    xt = x.reshape(B * S, D)
+    with jax.named_scope("ffn.route"):
+        gate, eidx, aux = _route(p["router"], xt, cfg.n_experts, cfg.top_k)
+    if _spiking_ffn_mode == "infer" and "plan_in" in p:
+        y = _experts_grouped(p, xt, gate, eidx, cfg)
+    else:
+        y = _experts_dense(p, xt, gate, eidx, cfg)
+    return y.reshape(B, S, D).astype(x.dtype), aux
+
+
+def _spiking_cfg(cfg: ArchConfig):
+    from repro.core.snn_layers import SpikingConfig
+
+    return SpikingConfig(T=cfg.spiking_T,
+                         weight_density=cfg.spiking_weight_density)
+
+
+def _combine(y_rows, gate):
+    """(T, k, D) expert outputs of each token's routed pairs -> (T, D):
+    the gate-weighted sum, over k in routing order for every row."""
+    with jax.named_scope("ffn.combine"):
+        return jnp.sum(y_rows.astype(jnp.float32) * gate[..., None], axis=1)
+
+
+def _experts_dense(p, xt, gate, eidx, cfg: ArchConfig):
+    """Every expert over every token (the differentiable float path, and
+    packed inference without plans); the gates pick each token's k."""
+    from repro.core.snn_layers import spiking_ffn_apply
+
+    ct = _ct(cfg)
+    xc = xt.astype(ct)
+
+    def one(wu, wd):
+        return spiking_ffn_apply(
+            {"w_in": wu.astype(ct), "w_out": wd.astype(ct)}, xc,
+            _spiking_cfg(cfg), mode=_spiking_ffn_mode,
+            use_kernel=jax.default_backend() == "tpu")
+
+    ye = jax.vmap(one)(p["wu"], p["wd"])                     # (E, T, D)
+    return _combine(ye[eidx, jnp.arange(xt.shape[0])[:, None]], gate)
+
+
+def _experts_grouped(p, xt, gate, eidx, cfg: ArchConfig):
+    """The serving path: routed rows sorted and padded by expert, both
+    GEMMs one grouped BSR call each (LIF fused into the up projection,
+    the rate decode into the down projection)."""
+    from repro.core.lif import direct_encode
+    from repro.core.packing import pack_spikes
+    from repro.kernels import ops
+    from repro.serve.policy import PACKED_DUAL
+
+    sc = _spiking_cfg(cfg)
+    n, d = xt.shape
+    with jax.named_scope("ffn.route"):
+        bm = ops.grouped_row_block(eidx.size, cfg.n_experts)
+        src, dest, groups = ops.group_rows(eidx, cfg.n_experts, bm)
+    with jax.named_scope("ffn.encode"):
+        words = pack_spikes(direct_encode(xt.astype(_ct(cfg)), sc.T,
+                                          v_th=sc.v_th, tau=sc.tau))
+        words = jnp.concatenate([words, jnp.zeros((1, d), words.dtype)])[src]
+    with jax.named_scope("ffn.up"):
+        hidden = ops.dispatch(
+            words, p["plan_in"], PACKED_DUAL, sc.T, fuse_lif=True,
+            v_th=sc.v_th, tau=sc.tau, n_out=p["wu"].shape[-1], groups=groups)
+    with jax.named_scope("ffn.down"):
+        out = ops.dispatch(hidden, p["plan_out"], PACKED_DUAL, sc.T,
+                           fuse_lif=False, n_out=d, groups=groups)
+    return _combine(out[dest], gate)
 
 
 def moe_apply(p, x, cfg: ArchConfig):

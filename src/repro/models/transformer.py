@@ -2,9 +2,12 @@
 
 Covers: gemma-2b, qwen3-14b, nemotron-4-340b, llama3.2-1b, hubert-xlarge
 (encoder), llava-next-mistral-7b (VLM stub frontend), mixtral-8x22b,
-phi3.5-moe.  Layers are scanned (compile-time O(1) in depth) with optional
-remat; the residual stream between layers carries SP sharding constraints
-(applied by the train/serve steps via shard hooks).
+phi3.5-moe, and Mellum2-12B-A2.5B (the benchmark's adapter: spiking
+experts, 3:1 sliding/full attention with YaRN on the full layers).  Layers
+are scanned (compile-time O(1) in depth) with optional remat; a model with
+per-layer attention kinds (``cfg.layer_types``) scans each layer's kind
+beside its weights.  The residual stream between layers carries SP
+sharding constraints (applied by the train/serve steps via shard hooks).
 """
 from __future__ import annotations
 
@@ -28,7 +31,9 @@ from .layers import (
     moe_apply,
     moe_axes,
     moe_init,
+    layer_kinds,
     rmsnorm,
+    spiking_moe_apply,
 )
 
 # A hook the distributed layer installs to constrain intermediate shardings
@@ -64,21 +69,26 @@ def block_axes(cfg: ArchConfig) -> dict:
     return ax
 
 
-def block_apply(p, x, cfg: ArchConfig, positions=None, cache=None):
+def block_apply(p, x, cfg: ArchConfig, positions=None, cache=None,
+                sliding=None):
     """Pre-norm transformer block. Returns (x, new_cache, aux_loss).
 
-    Its two halves are the named scopes ``attention`` and ``ffn`` of the
-    compiled program (norm and residual add included)."""
+    ``sliding``: the layer's kind in a `layer_types` model (see
+    `layers.attn_apply`).  Its two halves are the named scopes
+    ``attention`` and ``ffn`` of the compiled program (norm and residual
+    add included)."""
     with jax.named_scope("attention"):
         h, new_cache = attn_apply(
             p["attn"], rmsnorm(x, p["ln1"], cfg.norm_eps), cfg,
-            positions=positions, cache=cache,
+            positions=positions, cache=cache, sliding=sliding,
         )
         x = x + h
         x = _shard_hook(x, "residual")
     with jax.named_scope("ffn"):
         h2 = rmsnorm(x, p["ln2"], cfg.norm_eps)
-        if cfg.n_experts:
+        if cfg.n_experts and cfg.spiking_ffn:
+            h2, aux = spiking_moe_apply(p["moe"], h2, cfg)
+        elif cfg.n_experts:
             h2, aux = moe_apply(p["moe"], h2, cfg)
         else:
             h2, aux = mlp_apply(p["mlp"], h2, cfg), 0.0
@@ -124,10 +134,10 @@ def serving_params(p, cfg: ArchConfig) -> dict:
     the in-program cast gave (both round to nearest even).  Left as they
     are: ``embed`` (the packed spike encode and a tied head read it in
     f32), the norm scales, the MoE ``router``, and the spiking FFN's
-    ``wu``/``wd`` (`layers.attach_spiking_ffn_plans` builds its own
-    compute-dtype payload from them).  A leaf already in the compute dtype
-    is returned as it is, so the transform is idempotent.  Training keeps
-    its f32 masters and never calls this."""
+    ``wu``/``wd``, dense or expert (`layers.attach_spiking_ffn_plans`
+    builds its own compute-dtype payload from them).  A leaf already in the
+    compute dtype is returned as it is, so the transform is idempotent.
+    Training keeps its f32 masters and never calls this."""
     ct = _ct(cfg)
 
     def cast(a):
@@ -136,7 +146,7 @@ def serving_params(p, cfg: ArchConfig) -> dict:
     layers = dict(p["layers"])
     layers["attn"] = {k: cast(v) if k in ("wq", "wk", "wv", "wo") else v
                       for k, v in layers["attn"].items()}
-    if "moe" in layers:
+    if "moe" in layers and not cfg.spiking_ffn:
         layers["moe"] = {k: v if k == "router" else cast(v)
                          for k, v in layers["moe"].items()}
     elif not cfg.spiking_ffn:
@@ -169,21 +179,25 @@ def logical_axes(cfg: ArchConfig) -> dict:
 
 def _stack_forward(p_layers, x, cfg: ArchConfig, positions):
     """Scan the layer stack (training/prefill, no cache)."""
-    def body(carry, lp):
+    kinds = layer_kinds(cfg)
+
+    def body(carry, inp):
         x, aux = carry
-        x, _, a = block_apply(lp, x, cfg, positions=positions)
+        lp, sliding = inp
+        x, _, a = block_apply(lp, x, cfg, positions=positions,
+                              sliding=sliding)
         return (x, aux + a), None
 
     body_fn = jax.remat(body) if cfg.remat else body
     if cfg.scan_layers:
         (x, aux), _ = jax.lax.scan(
-            body_fn, (x, 0.0), p_layers, unroll=cfg.scan_unroll
+            body_fn, (x, 0.0), (p_layers, kinds), unroll=cfg.scan_unroll
         )
     else:
         carry = (x, 0.0)
         for i in range(cfg.n_layers):
-            lp = jax.tree.map(lambda a: a[i], p_layers)
-            carry, _ = body_fn(carry, lp)
+            inp = jax.tree.map(lambda a: a[i], (p_layers, kinds))
+            carry, _ = body_fn(carry, inp)
         x, aux = carry
     return x, aux
 
@@ -302,12 +316,14 @@ def _stack_forward_cached(p_layers, x, cfg: ArchConfig, positions, cache):
     """Scan layers threading per-layer KV cache (leading L dim)."""
     def body(carry, inp):
         x = carry
-        lp, ck, cv = inp
+        lp, ck, cv, sliding = inp
         lc = {"k": ck, "v": cv, "kv_pos": cache["kv_pos"], "pos": cache["pos"]}
-        x, nc, _ = block_apply(lp, x, cfg, positions=positions, cache=lc)
+        x, nc, _ = block_apply(lp, x, cfg, positions=positions, cache=lc,
+                               sliding=sliding)
         return x, (nc["k"], nc["v"])
 
-    x, (nk, nv) = jax.lax.scan(body, x, (p_layers, cache["k"], cache["v"]))
+    x, (nk, nv) = jax.lax.scan(
+        body, x, (p_layers, cache["k"], cache["v"], layer_kinds(cfg)))
     S = x.shape[1]
     s_cache = cache["k"].shape[2]
     kv_pos = jax.lax.dynamic_update_slice(

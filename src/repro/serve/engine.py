@@ -95,6 +95,7 @@ import numpy as np
 
 from repro.core.lif import direct_encode
 from repro.core.packing import pack_spikes
+from repro.models.layers import rows_independent
 
 from .batching import DenseCacheOps, PackedSpikeCache, spike_sparsity_of
 from .executor import make_executor, span
@@ -216,7 +217,10 @@ class Engine:
             )
         self.logit_trace_window = logit_trace_window
         self.logit_traces: dict[int, list[np.ndarray]] = {}
-        self.row_independent = cfg.n_experts == 0
+        # each row computed from that row alone: only capacity routing
+        # couples rows (`layers.rows_independent`); it gates cohort merges,
+        # batch alignment and the prefix cache
+        self.row_independent = rows_independent(cfg)
         self._user_batch_align = batch_align
         self.merge_cohorts = merge_cohorts and self.row_independent
         self.metrics = EngineMetrics()

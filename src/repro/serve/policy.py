@@ -528,12 +528,14 @@ class ExecutionPolicy:
             # Same arch/T by construction: the draft is validated against the
             # SAME cfg (one engine, one param tree, one spiking_T).
             spec.draft.validate_for(cfg)
-            if getattr(cfg, "n_experts", 0):
+            from repro.models.layers import rows_independent
+
+            if not rows_independent(cfg):
                 raise ValueError(
                     "speculation needs row-independent decode (acceptance "
                     f"rolls individual rows back), but {cfg.name} routes "
-                    f"across {cfg.n_experts} experts — capacity routing "
-                    "couples batch rows"
+                    f"across {cfg.n_experts} experts with a capacity, which "
+                    "drops tokens by what the rest of the batch routes"
                 )
             if getattr(cfg, "attn", "causal") != "causal":
                 raise ValueError(

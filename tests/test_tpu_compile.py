@@ -15,6 +15,10 @@ the widths the serving path runs them:
 * the dense-weight kernels (`ftp_spmm`, `ftp_spmm_fused_lif`) at the same
   widths;
 * the paper's Table II T-HFF layer (T=4, M=784, N=K=3072);
+* the grouped BSR kernel of the spiking experts at Mellum2's widths (64
+  experts, 2304 x 896 and back), for the routed rows of a one- and a
+  four-token decode and of 2048- and 16384-token prefills (the last,
+  with its 1087 row tiles, the largest scalar-prefetched activity map);
 * the BSR kernel under a data-only mesh of two described chips, where the
   whole plan is replicated and Mosaic, which cannot partition a kernel
   itself, must get it per data shard.
@@ -172,3 +176,38 @@ def test_bsr_kernel_compiles_under_a_data_only_mesh(v5e, K, N, fuse_lif):
             _spikes(8, K, NamedSharding(mesh, P("data", None))),
             _plan(K, N, DENSITY, NamedSharding(mesh, P())),
         )
+
+
+E_MOE, D_MOE, F_MOE, TOP_K = 64, 2304, 896, 8
+
+
+def _expert_plan(K, N, sharding):
+    """`_plan`'s shapes stacked over the experts."""
+    p = _plan(K, N, DENSITY, sharding)
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        (E_MOE,) + a.shape, a.dtype, sharding=sharding), p)
+
+
+@pytest.mark.parametrize("tokens", [1, 4, 2048, 16384])
+@pytest.mark.parametrize(
+    "K,N,fuse_lif",
+    [(D_MOE, F_MOE, True), (F_MOE, D_MOE, False)],
+    ids=["expert_in_lif", "expert_out_rates"],
+)
+def test_grouped_bsr_kernel_compiles_at_mellum2_expert_widths(
+    one_chip, K, N, fuse_lif, tokens
+):
+    rows = tokens * TOP_K
+    bm = ops.grouped_row_block(rows, E_MOE)
+    nm = ops.grouped_tiles(rows, E_MOE, bm)
+
+    def layer(a, plan, groups):
+        return ops.dispatch(a, plan, PACKED_DUAL, T, fuse_lif=fuse_lif,
+                            n_out=N, groups=groups, interpret=False)
+
+    text = _compile_text(
+        layer, _spikes(nm * bm, K, one_chip), _expert_plan(K, N, one_chip),
+        jax.ShapeDtypeStruct((nm + 1,), jnp.int32, sharding=one_chip),
+    )
+    # the trace counts the kernel by this prefix (bench/trace_reduce.py)
+    assert "%_bsr_call_grouped" in text
